@@ -7,11 +7,11 @@ export PYTHONPATH := src:$(PYTHONPATH)
 
 # tier-1 verify (ROADMAP.md)
 test:
-	$(PYTHON) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -x -q
 
 # quick signal: core engine + system + planner only
 test-fast:
-	$(PYTHON) -m pytest -x -q tests/test_engine.py tests/test_scheduler.py \
+	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -x -q tests/test_engine.py tests/test_scheduler.py \
 	    tests/test_system.py tests/test_planner.py tests/test_channels.py
 
 bench-smoke:

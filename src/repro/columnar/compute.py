@@ -42,7 +42,7 @@ def filter_table(table: ColumnTable, predicate: Union[str, Expr],
         numeric = {n: table.column(n) for n in table.column_names
                    if table.column(n).kind != "utf8"}
         if numeric:
-            idx = np.asarray(kops.compact_indices(mask))
+            idx = kops.compact_indices(mask)
         else:
             idx = np.nonzero(mask)[0]
         return table.take(idx)
@@ -153,7 +153,7 @@ def group_by(table: ColumnTable, keys: Sequence[str],
         if backend == "jax":
             from repro.kernels import ops as kops
 
-            agg = np.asarray(kops.groupby_aggregate(vals, codes, n_groups, fn))
+            agg = kops.groupby_aggregate_rows(vals, codes, n_groups, fn)
         else:
             if fn in ("sum", "mean"):
                 sums = np.bincount(codes, weights=vals, minlength=n_groups)

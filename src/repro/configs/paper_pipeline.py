@@ -37,24 +37,33 @@ def smoke_config() -> PipelineConfig:
                                rows_per_file=5_000)
 
 
-def build_project(cfg: PipelineConfig):
-    """Instantiate the DAG from the config (used by tests/benchmarks)."""
+def build_project(cfg: PipelineConfig, backend: str = "numpy"):
+    """Instantiate the DAG from the config (used by tests/benchmarks).
+
+    The filter is row-local, so a sharded scan stays sharded through it,
+    and the aggregation is a declared group-by, so each shard aggregates
+    locally and only per-country states meet at the combine.
+    ``backend="jax"`` runs the filter's compaction and both halves of the
+    group-by on the Pallas kernels (`repro.kernels`)."""
     import repro as bp
     from repro.columnar import compute
 
     proj = bp.Project(cfg.name)
     filt = "country IN (%s)" % ",".join(f"'{c}'" for c in cfg.countries)
+    aggs = {"usd": ("usd", "sum")}
 
-    @proj.model()
+    @proj.model(rowwise=True)
     @proj.python(cfg.envs[0][0], dict(cfg.envs[0][1]))
     def euro_selection(data=bp.Model(cfg.source_table,
                                      columns=list(cfg.pushdown_columns),
                                      filter=cfg.date_filter)):
-        return compute.filter_table(data, filt)
+        return compute.filter_table(data, filt, backend=backend)
 
-    @proj.model(materialize=True)
+    @proj.model(materialize=True,
+                combinable=bp.GroupByCombine(["country"], aggs,
+                                             backend=backend))
     @proj.python(cfg.envs[1][0], dict(cfg.envs[1][1]))
     def usd_by_country(data=bp.Model("euro_selection")):
-        return compute.group_by(data, ["country"], {"usd": ("usd", "sum")})
+        return compute.group_by(data, ["country"], aggs, backend=backend)
 
     return proj

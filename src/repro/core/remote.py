@@ -701,7 +701,13 @@ class RemoteCluster:
     ``project`` is a ``load_project_spec`` string handed to each daemon so
     workers can resolve FunctionSpecs by name (the control plane only ships
     plan metadata, never code). A heartbeat thread detects dead processes
-    and feeds ``engine.worker_lost`` for proactive recovery."""
+    and feeds ``engine.worker_lost`` for proactive recovery.
+
+    A TPU belongs to one process, so daemons do not share a chip: on a
+    one-chip host at most one of them can open it, and a ``backend="jax"``
+    operator in any other raises (``repro.kernels.ops``) instead of running
+    on the CPU. Device operators run on a ``LocalCluster`` inside the
+    process that holds the chip."""
 
     def __init__(self, catalog, object_store, scratch_root: str,
                  n_workers: int = 2, memory_gb: float = 4.0,

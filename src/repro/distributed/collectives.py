@@ -10,12 +10,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exposes it under jax.experimental
-    from jax.experimental.shard_map import shard_map
 
 
 def compressed_psum_grads(grads, mesh: Mesh, axis: str = "pod",

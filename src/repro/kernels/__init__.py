@@ -5,7 +5,8 @@
 #                     usd_by_country hot spot; one-hot MXU reduction)
 #   filter_compact  — predicate compaction (the paper's euro_selection hot
 #                     spot; two-pass count + permute, no atomics)
-# ops.py = jit'd wrappers (interpret on CPU, compiled on TPU);
+# ops.py = jit'd wrappers (compiled on TPU, interpret mode on the CPU
+#          platform, an error anywhere else);
 # ref.py = pure-jnp oracles (the correctness contract for tests).
 from repro.kernels import ops, ref
 

@@ -1,6 +1,9 @@
 """jit'd public wrappers over the Pallas kernels (+ padding & dispatch).
 
-On CPU (this container) kernels run in interpret mode; on TPU they compile.
+On a TPU the kernels compile through Mosaic. On the CPU platform (the
+tests run with `JAX_PLATFORMS=cpu`) they run in Pallas interpret mode. Any
+other platform is an error, and so is a CPU fallback in a process that
+could not open the host's TPU: the kernels never hide a missing device.
 `ref.py` holds the pure-jnp oracles tests compare against.
 """
 from __future__ import annotations
@@ -10,14 +13,41 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import filter_compact as _fc
 from repro.kernels import flash_attention as _fa
 from repro.kernels import groupby_agg as _gb
 
+# Row block of the filter and group-by kernels. XLA tiles a long rank-1
+# 32-bit array T(1024), and Mosaic refuses a block whose tiling differs.
+ROW_BLOCK = 1024
+# The group-by kernel holds a (ROW_BLOCK, groups) f32 one-hot and a
+# (groups,) accumulator in VMEM. On a TPU v5e every aggregate compiles at
+# 4096 groups, and count runs out of scoped VMEM at 8192.
+MAX_GROUPS = 4096
+
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform != "cpu":
+        raise RuntimeError(f"the Pallas kernels compile for 'tpu' and run in "
+                           f"interpret mode on 'cpu'; platform {platform!r} "
+                           "has neither")
+    try:
+        jax.devices("tpu")
+    except RuntimeError as e:
+        # JAX falls back to the CPU on its own when the TPU cannot be
+        # opened, e.g. because another process holds the chip
+        if "failed to initialize" in str(e):
+            raise RuntimeError(
+                "this process runs on the CPU because it could not open the "
+                f"TPU ({e}). A chip belongs to one process: run device "
+                "operators in the process that holds it, or set "
+                "JAX_PLATFORMS=cpu to interpret the kernels on the CPU") from e
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -47,36 +77,45 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _pad_to(x: jax.Array, mult: int, fill) -> jax.Array:
+    """Pad the leading dim to a positive multiple of `mult`."""
     n = x.shape[0]
-    p = (-n) % mult
+    p = max((n + mult - 1) // mult, 1) * mult - n
     if p == 0:
         return x
     return jnp.concatenate([x, jnp.full((p,), fill, x.dtype)])
 
 
-@functools.partial(jax.jit, static_argnames=("n_groups", "fn", "block_n"))
+def _lane_pad(n_groups: int) -> int:
+    return max((n_groups + 127) // 128 * 128, 128)
+
+
+def _check_groups(n_groups: int) -> None:
+    if n_groups > MAX_GROUPS:
+        raise ValueError(
+            f"group-by over {n_groups} groups exceeds the device kernel's "
+            f"limit of MAX_GROUPS={MAX_GROUPS}: its ({ROW_BLOCK}, groups) "
+            "one-hot must fit VMEM; aggregate on the host (backend='numpy')")
+
+
+@functools.partial(jax.jit, static_argnames=("n_groups", "fn"))
 def groupby_aggregate(values: jax.Array, codes: jax.Array, n_groups: int,
-                      fn: str = "sum", block_n: int = 1024) -> jax.Array:
+                      fn: str = "sum") -> jax.Array:
     """Segment aggregate via the Pallas kernel. values (N,), codes (N,)."""
-    ng_pad = max((n_groups + 127) // 128 * 128, 128)
-    bn = min(block_n, max(128, ng_pad))
-    vals = _pad_to(values.astype(jnp.float32), bn, 0.0)
-    cds = _pad_to(codes.astype(jnp.int32), bn, ng_pad - 1 if fn in ("min", "max")
-                  else n_groups)
-    # padded rows: for sum/count they carry code==n_groups (contribute to a
-    # group we slice off when n_groups < ng_pad) ... unless n_groups == ng_pad;
-    # use value-neutral padding instead: sum pads 0.0, min/max pad +-inf codes
-    # to the last real group with neutral values.
-    if fn in ("min", "max"):
-        neutral = jnp.inf if fn == "min" else -jnp.inf
-        vals = vals.at[values.shape[0]:].set(neutral)
-        cds = cds.at[values.shape[0]:].set(0)
+    _check_groups(n_groups)
+    ng_pad = _lane_pad(n_groups)
+    vals = _pad_to(values.astype(jnp.float32), ROW_BLOCK, 0.0)
+    # padded rows get code n_groups: a group that is sliced off
+    # (n_groups < ng_pad) or outside the kernel's one-hot
+    cds = _pad_to(codes.astype(jnp.int32), ROW_BLOCK, n_groups)
     if fn == "mean":
-        s = _gb.groupby_pallas(vals, cds, ng_pad, "sum", bn, _interpret())
-        c = _gb.groupby_pallas(vals, cds, ng_pad, "count", bn, _interpret())
+        s = _gb.groupby_pallas(vals, cds, ng_pad, "sum", ROW_BLOCK,
+                               _interpret())
+        c = _gb.groupby_pallas(vals, cds, ng_pad, "count", ROW_BLOCK,
+                               _interpret())
         out = s / jnp.maximum(c, 1.0)
     else:
-        out = _gb.groupby_pallas(vals, cds, ng_pad, fn, bn, _interpret())
+        out = _gb.groupby_pallas(vals, cds, ng_pad, fn, ROW_BLOCK,
+                                 _interpret())
     return out[:n_groups]
 
 
@@ -93,7 +132,7 @@ def combine_aggregate(parts: jax.Array, n_groups: int, fn: str = "sum",
     neutral = {"sum": 0.0, "count": 0.0,
                "min": jnp.inf, "max": -jnp.inf}[fn]
     p, g = parts.shape
-    g_pad = max((g + 127) // 128 * 128, 128)
+    g_pad = _lane_pad(g)
     bp = min(block_p, max(p, 1))
     p_pad = (p + bp - 1) // bp * bp
     padded = jnp.full((p_pad, g_pad), neutral, jnp.float32)
@@ -107,28 +146,59 @@ def combine_aggregate(parts: jax.Array, n_groups: int, fn: str = "sum",
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("block_n",))
-def compact(mask: jax.Array, block_n: int = 1024
-            ) -> Tuple[jax.Array, jax.Array]:
+@jax.jit
+def compact(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Returns (indices (N,), count): indices[:count] = survivors ascending."""
     n = mask.shape[0]
-    m = _pad_to(mask.astype(jnp.bool_), min(block_n, max(n, 8)), False)
-    bn = min(block_n, m.shape[0])
-    counts = _fc.block_counts(m, bn, _interpret())           # (nb,)
-    tiles = _fc.block_compact(m, bn, _interpret())           # (nb, bn)
-    offsets = jnp.cumsum(counts) - counts                    # exclusive
-    nb = counts.shape[0]
-    slot = jnp.arange(bn)[None, :]
+    m = _pad_to(mask.astype(jnp.int32), ROW_BLOCK, 0)[None, :]    # (1, N')
+    counts = _fc.block_counts(m, ROW_BLOCK, _interpret())         # (nb,)
+    tiles = _fc.block_compact(m, ROW_BLOCK, _interpret())         # (nb, bn)
+    offsets = jnp.cumsum(counts) - counts                         # exclusive
+    slot = jnp.arange(ROW_BLOCK)[None, :]
     valid = slot < counts[:, None]
-    dst = jnp.where(valid, offsets[:, None] + slot, n)       # (nb, bn)
+    dst = jnp.where(valid, offsets[:, None] + slot, n)            # (nb, bn)
     out = jnp.full((n + 1,), n - 1, jnp.int32)
     out = out.at[dst.reshape(-1)].set(tiles.reshape(-1))
     return out[:n], jnp.sum(counts)
 
 
-def compact_indices(mask) -> jax.Array:
-    """Host-friendly: returns a numpy array of the surviving indices."""
-    import numpy as np
+# ---------------------------------------------------------------------------
+# host-facing entry points (numpy in, numpy out)
+# ---------------------------------------------------------------------------
+#
+# A streamed scan hands the operators chunks whose lengths all differ, and a
+# jitted wrapper compiles once per input shape. These entry points pad the
+# rows to a power of two of at least ROW_BLOCK, with rows that change no
+# result, so a whole scan compiles a few programs rather than one per chunk.
 
-    idx, count = compact(jnp.asarray(np.asarray(mask)))
+
+def _bucket(n: int) -> int:
+    return max(ROW_BLOCK, 1 << max(n - 1, 0).bit_length())
+
+
+def _pad_rows(x: np.ndarray, rows: int, fill) -> np.ndarray:
+    out = np.full(rows, fill, x.dtype)
+    out[:x.shape[0]] = x
+    return out
+
+
+def compact_indices(mask) -> np.ndarray:
+    """The surviving indices of a host boolean mask, ascending."""
+    mask = np.asarray(mask, bool)
+    idx, count = compact(jnp.asarray(_pad_rows(mask, _bucket(mask.size),
+                                               False)))
     return np.asarray(idx)[: int(count)]
+
+
+def groupby_aggregate_rows(values, codes, n_groups: int,
+                           fn: str = "sum") -> np.ndarray:
+    """`groupby_aggregate` of host arrays. The group count is padded to the
+    kernel's lane multiple as well; padded rows take code `ng_pad`, outside
+    the kernel's one-hot, so they reach no group."""
+    _check_groups(n_groups)
+    ng_pad = _lane_pad(n_groups)
+    rows = _bucket(len(values))
+    vals = _pad_rows(np.asarray(values, np.float32), rows, 0.0)
+    cds = _pad_rows(np.asarray(codes, np.int32), rows, ng_pad)
+    out = groupby_aggregate(jnp.asarray(vals), jnp.asarray(cds), ng_pad, fn)
+    return np.asarray(out)[:n_groups]

@@ -31,7 +31,8 @@ import jax
 from repro.configs import (ARCH_IDS, SHAPES, applicable_shapes, get_config)
 from repro.distributed.sharding import make_sharding_plan
 from repro.launch import roofline as rl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND,
+                               make_production_mesh)
 from repro.models import build_model
 from repro.models import layers as L
 from repro.train import serve_step as ss
@@ -164,7 +165,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
         else:
             costs = rl.extract_costs(compiled, mesh.devices.size)
     roof = rl.analyze(compiled, cfg, shape, mesh_name, mesh.devices.size,
-                      variant, costs=costs, memory_compiled=compiled)
+                      PRODUCTION_DEVICE_KIND, variant, costs=costs,
+                      memory_compiled=compiled)
     record = roof.to_json()
     record["compile_seconds"] = round(time.time() - t0, 2)
     record["status"] = "ok"

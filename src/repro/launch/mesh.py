@@ -13,12 +13,18 @@ from __future__ import annotations
 
 
 import jax
+from jax.sharding import AxisType
+
+# what JAX reports as `device_kind` for the production mesh's chips
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding plan places arrays with
+    # with_sharding_constraint, which refuses Explicit axes
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(devices_or_count=None, model_parallelism: int = 16,

@@ -14,8 +14,8 @@ collective-permute, resolve each op's replica-group size, and apply ring
 transfer factors (AR: 2S(G-1)/G; AG/A2A: S(G-1)/G; RS: operand (G-1)/G;
 permute: S).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per assignment).
+Hardware peaks come from `PEAKS`, keyed by the `device_kind` JAX reports;
+a chip that is not in the table is an error, not a default.
 """
 from __future__ import annotations
 
@@ -23,10 +23,30 @@ import dataclasses
 import re
 from typing import Dict, Tuple
 
-# v5e constants (assignment-specified)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float         # bf16 FLOP/s per chip
+    hbm_bw: float        # HBM bytes/s per chip
+    ici_bw: float        # interconnect bytes/s per link
+    source: str
+
+
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip interconnect "
+               "over 4 links (50 GB/s per link)"),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peaks for device kind {device_kind!r}; known: "
+                         f"{sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -191,14 +211,15 @@ def extrapolate_costs(costs_g2, costs_g4, g2: int, g4: int, g_full: int
 
 
 def analyze(compiled, cfg, shape, mesh_name: str, n_devices: int,
-            variant: str = "baseline", costs=None,
+            device_kind: str, variant: str = "baseline", costs=None,
             memory_compiled=None) -> Roofline:
+    peaks = peaks_for(device_kind)
     flops, byts, colls = (costs if costs is not None
                           else extract_costs(compiled, n_devices))
     wire = sum(c.wire_bytes for c in colls.values())
-    t_c = flops / PEAK_FLOPS
-    t_m = byts / HBM_BW
-    t_x = wire / ICI_BW
+    t_c = flops / peaks.flops
+    t_m = byts / peaks.hbm_bw
+    t_x = wire / peaks.ici_bw
     bottleneck = max((("compute", t_c), ("memory", t_m),
                       ("collective", t_x)), key=lambda kv: kv[1])[0]
     mf = model_flops(cfg, shape)

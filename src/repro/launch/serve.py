@@ -136,7 +136,9 @@ def main() -> None:
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--concurrency", type=int, default=4)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.pipeline:
         serve_pipeline_main(args)
         return

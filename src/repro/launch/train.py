@@ -30,6 +30,7 @@ from repro.core.runtime import Client, LocalCluster, execute_run
 from repro.data.pipeline import TokenBatchStream, build_data_project
 from repro.data.synthetic import make_corpus_table
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import checkpoint as ckpt
 from repro.train import train_step as ts
@@ -80,6 +81,7 @@ def main() -> None:
     ap.add_argument("--n-docs", type=int, default=256)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     workdir = args.workdir or tempfile.mkdtemp(prefix="repro_train_")
     os.makedirs(workdir, exist_ok=True)

@@ -35,6 +35,7 @@ class ParamSpec:
     axes: Tuple[Optional[str], ...]
     init: str = "normal"       # normal | zeros | ones | small
     scale: float = 1.0
+    fan_in: int = 0            # contracted input size; 0: second-to-last dim
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -63,7 +64,8 @@ def init_params(rng: jax.Array, specs: Specs, dtype=jnp.bfloat16) -> Dict:
         elif spec.init == "ones":
             arr = jnp.ones(spec.shape, dtype)
         else:
-            fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+            fan_in = spec.fan_in or (spec.shape[-2] if len(spec.shape) >= 2
+                                     else spec.shape[-1])
             std = spec.scale / math.sqrt(max(fan_in, 1))
             if spec.init == "small":
                 std = 0.02 * spec.scale
@@ -87,8 +89,8 @@ def param_bytes(specs: Specs, bytes_per_el: int = 2) -> int:
 
 def stacked(specs: Specs, n: int, prefix: str = "") -> Specs:
     """Add a leading scan ('layers') dim to every spec."""
-    return {prefix + p: ParamSpec((n,) + s.shape, ("layers",) + s.axes,
-                                  s.init, s.scale)
+    return {prefix + p: dataclasses.replace(s, shape=(n,) + s.shape,
+                                            axes=("layers",) + s.axes)
             for p, s in specs.items()}
 
 

@@ -23,12 +23,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exposes it under jax.experimental
-    from jax.experimental.shard_map import shard_map
 
 from repro.configs.common import ModelConfig
 from repro.models.layers import ParamSpec, Specs, activation

@@ -23,10 +23,14 @@ import math
 def _cross_specs(cfg: ModelConfig, path: str) -> Specs:
     d, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     return {
-        f"{path}/wq": ParamSpec((d, H, hd), ("embed", "heads", "head_dim")),
-        f"{path}/wk": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim")),
-        f"{path}/wv": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim")),
-        f"{path}/wo": ParamSpec((H, hd, d), ("heads", "head_dim", "embed")),
+        f"{path}/wq": ParamSpec((d, H, hd), ("embed", "heads", "head_dim"),
+                                fan_in=d),
+        f"{path}/wk": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"),
+                                fan_in=d),
+        f"{path}/wv": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"),
+                                fan_in=d),
+        f"{path}/wo": ParamSpec((H, hd, d), ("heads", "head_dim", "embed"),
+                                fan_in=H * hd),
     }
 
 

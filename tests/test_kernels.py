@@ -95,16 +95,23 @@ def test_groupby_matches_ref(n, g, fn):
     rng = np.random.default_rng(n * 31 + g)
     vals = jnp.asarray(rng.standard_normal(n).astype(np.float32))
     codes = jnp.asarray(rng.integers(0, g, n).astype(np.int32))
-    out = ops.groupby_aggregate(vals, codes, g, fn, block_n=256)
+    out = ops.groupby_aggregate(vals, codes, g, fn)
     want = ref.ref_groupby(vals, codes, g, fn)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
 
+def test_groupby_rejects_more_groups_than_vmem_holds():
+    vals = jnp.ones(8, jnp.float32)
+    codes = jnp.zeros(8, jnp.int32)
+    with pytest.raises(ValueError, match="MAX_GROUPS"):
+        ops.groupby_aggregate(vals, codes, ops.MAX_GROUPS + 1)
+
+
 def test_groupby_empty_groups():
     vals = jnp.asarray(np.ones(64, np.float32))
     codes = jnp.asarray(np.zeros(64, np.int32))
-    out = ops.groupby_aggregate(vals, codes, 5, "sum", block_n=64)
+    out = ops.groupby_aggregate(vals, codes, 5, "sum")
     np.testing.assert_allclose(np.asarray(out), [64, 0, 0, 0, 0])
 
 
@@ -118,7 +125,7 @@ def test_groupby_empty_groups():
 def test_compact_matches_nonzero(n, p):
     rng = np.random.default_rng(int(n * 1000 * (p + 1)))
     mask = jnp.asarray(rng.random(n) < p)
-    idx, cnt = ops.compact(mask, block_n=256)
+    idx, cnt = ops.compact(mask)
     want = np.nonzero(np.asarray(mask))[0]
     assert int(cnt) == len(want)
     np.testing.assert_array_equal(np.asarray(idx)[:int(cnt)], want)
@@ -126,12 +133,44 @@ def test_compact_matches_nonzero(n, p):
 
 def test_compact_all_and_none():
     mask = jnp.asarray(np.ones(512, bool))
-    idx, cnt = ops.compact(mask, block_n=128)
+    idx, cnt = ops.compact(mask)
     assert int(cnt) == 512
     np.testing.assert_array_equal(np.asarray(idx), np.arange(512))
     mask0 = jnp.asarray(np.zeros(512, bool))
-    _, cnt0 = ops.compact(mask0, block_n=128)
+    _, cnt0 = ops.compact(mask0)
     assert int(cnt0) == 0
+
+
+@pytest.mark.parametrize("fn", ["sum", "count", "mean", "min", "max"])
+@pytest.mark.parametrize("n,g", [(1, 1), (1500, 6), (3000, 128)])
+def test_groupby_rows_matches_ref(n, g, fn):
+    """Host entry point: padded rows and groups reach no real group, also
+    when the group count is already a lane multiple."""
+    rng = np.random.default_rng(n + g)
+    vals = rng.standard_normal(n)
+    codes = rng.integers(0, g, n)
+    out = ops.groupby_aggregate_rows(vals, codes, g, fn)
+    want = ref.ref_groupby(jnp.asarray(vals, jnp.float32),
+                           jnp.asarray(codes, jnp.int32), g, fn)
+    assert out.shape == (g,)
+    np.testing.assert_allclose(out, np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def test_host_entry_points_compile_per_bucket_not_per_length():
+    """Chunks of a streamed scan differ in length; the host entry points pad
+    them to a power-of-two bucket, so 40 lengths in (1024, 2048] compile one
+    program per kernel wrapper."""
+    rng = np.random.default_rng(3)
+    lengths = range(1100, 1140)
+    compact0 = ops.compact._cache_size()
+    groupby0 = ops.groupby_aggregate._cache_size()
+    for n in lengths:
+        mask = rng.random(n) < 0.3
+        np.testing.assert_array_equal(ops.compact_indices(mask),
+                                      np.nonzero(mask)[0])
+        ops.groupby_aggregate_rows(np.ones(n), rng.integers(0, 6, n), 6)
+    assert ops.compact._cache_size() - compact0 <= 1
+    assert ops.groupby_aggregate._cache_size() - groupby0 <= 1
 
 
 def test_compute_jax_backend_routes_through_kernels(lakehouse):
@@ -150,3 +189,65 @@ def test_compute_jax_backend_routes_through_kernels(lakehouse):
                           backend="numpy")
     np.testing.assert_allclose(ga.column("s").to_numpy(),
                                gb.column("s").to_numpy(), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# device choice
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("platform,tpu_error,expect", [
+    ("tpu", None, False),
+    ("cpu", "Unknown backend tpu. Available backends are ['cpu']", True),
+    ("cpu", "Backend 'tpu' failed to initialize: TPU is busy", RuntimeError),
+    ("gpu", None, RuntimeError),
+])
+def test_interpret_mode_only_on_the_cpu_platform(monkeypatch, platform,
+                                                 tpu_error, expect):
+    """Interpret mode is chosen on the CPU platform only, and never for a
+    process that fell back to the CPU because it could not open the TPU."""
+    def devices(backend=None):
+        if tpu_error:
+            raise RuntimeError(tpu_error)
+        return []
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(jax, "devices", devices)
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError):
+            ops._interpret()
+    else:
+        assert ops._interpret() is expect
+
+
+def test_import_repro_loads_no_jax_and_sets_no_cache():
+    """A process that only imports the package leaves the chip and the
+    compile cache alone: both are the entry points' business."""
+    import subprocess
+    import sys
+
+    code = ("import sys, repro; "
+            "assert 'jax' not in sys.modules, 'import repro loaded jax'")
+    subprocess.run([sys.executable, "-c", code], check=True)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.enable_compile_cache() == env_dir
+        assert updates == []        # JAX reads the variable itself
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        where = compile_cache.enable_compile_cache()
+        assert updates == [("jax_compilation_cache_dir", where)]
+        # one fixed directory at the checkout's root, never committed
+        root = compile_cache.CACHE_DIR.parent
+        assert where == str(compile_cache.CACHE_DIR)
+        assert (root / "src" / "repro").is_dir()
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()

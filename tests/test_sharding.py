@@ -109,6 +109,15 @@ def test_extrapolation_linear():
     assert colls["all-reduce"].wire_bytes == pytest.approx(40 + 20 * 8)
 
 
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
+
+    v5e = rl.peaks_for(PRODUCTION_DEVICE_KIND)
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9) and v5e.source
+    with pytest.raises(ValueError, match="no peaks"):
+        rl.peaks_for("cpu")
+
+
 def test_model_flops_formulas():
     cfg = get_config("codeqwen1.5-7b")
     t = rl.model_flops(cfg, SHAPES["train_4k"])
@@ -129,6 +138,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, dataclasses
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import smoke_config, SHAPES
 from repro.distributed.sharding import make_sharding_plan
 from repro.models import build_model
@@ -138,7 +148,8 @@ from repro.launch import roofline as rl
 results = {}
 for mesh_shape, axes in (((4, 2), ("data", "model")),
                          ((2, 2, 2), ("pod", "data", "model"))):
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = jax.make_mesh(mesh_shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
     cfg = smoke_config("gemma2-27b")
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64,
                                 global_batch=8)
