@@ -13,6 +13,7 @@ from typing import Dict, List, Sequence, Tuple, Union
 import numpy as np
 
 from repro.columnar.expr import Expr, parse_predicate
+from repro.core.spans import span
 from repro.columnar.table import (Column, ColumnTable, numeric_column,
                                   pack_validity)
 # the sharded data plane's single merge point: row-concatenate shard tables
@@ -138,9 +139,12 @@ def group_by(table: ColumnTable, keys: Sequence[str],
             data[out_name] = numeric_column(
                 np.array([], dtype=np.int64 if is_int else np.float64))
         return ColumnTable(data)
-    codes, first = _encode_keys(table, keys)
-    n_groups = len(first)
-    out: Dict[str, Column] = {k: table.column(k).take(first) for k in keys}
+    with span("op.keys", rows=table.num_rows) as sp:
+        codes, first = _encode_keys(table, keys)
+        n_groups = len(first)
+        out: Dict[str, Column] = {k: table.column(k).take(first)
+                                  for k in keys}
+        sp.set(groups=n_groups)
     for out_name, (src, fn) in aggs.items():
         if fn not in AGG_FUNCS:
             raise ValueError(f"unknown agg {fn!r}; supported: {AGG_FUNCS}")
@@ -277,20 +281,28 @@ def _combine_states_jax(parts: Sequence[ColumnTable], state: ColumnTable,
     across the part axis by the Pallas combine accumulator."""
     from repro.kernels import ops as kops
 
-    codes, first = _encode_keys(state, keys)
-    n_groups = len(first)
+    with span("op.keys", rows=state.num_rows) as sp:
+        codes, first = _encode_keys(state, keys)
+        n_groups = len(first)
+        out: Dict[str, Column] = {k: state.column(k).take(first)
+                                  for k in keys}
+        sp.set(groups=n_groups)
     # `state` is the parts concatenated in shard order; each state row's part
     # index makes every (part, group) cell a single writer
     row_part = np.repeat(np.arange(len(parts)),
                          [p.num_rows for p in parts])
-    out: Dict[str, Column] = {k: state.column(k).take(first) for k in keys}
     for out_name, (src, fn) in merge_aggs.items():
         src_col = state.column(src)
         vals = src_col.data.astype(np.float64)
         neutral = {"sum": 0.0, "min": np.inf, "max": -np.inf}[fn]
         dense = np.full((len(parts), n_groups), neutral, dtype=np.float64)
         dense[row_part, codes] = vals
-        agg = np.asarray(kops.combine_aggregate(dense, n_groups, fn))
+        # the device call and the wait for its result: the jitted wrapper
+        # pads on the device, np.asarray copies the result back
+        with span("op.kernel", kernel="combine_aggregate", fn=fn,
+                  parts=dense.shape[0], bytes_in=dense.nbytes,
+                  bytes_out=4 * n_groups, compiles=0):
+            agg = np.asarray(kops.combine_aggregate(dense, n_groups, fn))
         if np.issubdtype(src_col.dtype, np.integer):
             agg = agg.astype(np.int64)
         out[out_name] = numeric_column(agg)

@@ -39,7 +39,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Set, Tuple, TYPE_CHECKING
 
-from repro.core import defaults
+from repro.core import defaults, spans
 from repro.core.channels import TableHandle
 from repro.core.errors import DeadlineExceeded
 from repro.core.journal import RunJournal
@@ -247,6 +247,9 @@ class _RunState:
         self.done: Set[str] = set()
         self.inflight: Dict[str, _Inflight] = {}
         self.queued: Set[str] = set()   # tids on the engine's ready heap
+        # wall-clock moment each queued task became ready: the start of its
+        # `task.wait` span
+        self.ready_at: Dict[str, float] = {}
         self.placements: Dict[str, str] = {}
         self.group_worker: Dict[str, str] = {}
         self.durations: List[float] = []
@@ -602,6 +605,7 @@ class ExecutionEngine:
         if tid in state.queued:
             return
         state.queued.add(tid)
+        state.ready_at[tid] = time.time()
         now = time.perf_counter()
         seq = next(self._seq)
         heapq.heappush(self._ready,
@@ -718,9 +722,11 @@ class ExecutionEngine:
                                     {"reason": "straggler"}))
         elif info.timer is None:
             self._arm_speculation_timer(state, tid, info)
+        # a twin is ready the moment speculation picks it
+        ready = state.ready_at.pop(tid, None) or time.time()
         try:
             self._pool.submit(self._attempt, state, tid, task, worker,
-                              state.attempts[tid])
+                              state.attempts[tid], ready, speculative)
         except RuntimeError:
             # pool already shut down (engine closed between the run abort
             # and this dispatch): the attempt will never execute, so its
@@ -779,7 +785,8 @@ class ExecutionEngine:
 
     # -- the attempt itself (pool thread, no engine lock) -------------------
     def _attempt(self, state: _RunState, tid: str, task,
-                 worker: Worker, attempt: int) -> None:
+                 worker: Worker, attempt: int, ready: float,
+                 speculative: bool) -> None:
         if state.finished.is_set():
             # the run was aborted (engine closed / failed) between dispatch
             # and execution: skip the work, but still release the slot and
@@ -796,6 +803,11 @@ class ExecutionEngine:
             with self._lock:
                 put_channel = self._put_channel(state, task)
                 edge_channels = self._bind_channels(state, task, worker)
+            now = time.time()
+            state.client.emit(Event("spans", tid, worker.worker_id, {
+                "spans": [spans.interval(
+                    "task.wait", ready, now - ready, state.plan.run_id, tid,
+                    attempt=attempt, speculative=speculative)]}))
             handle = worker.execute(state.plan, task, state.handles,
                                     state.client, put_channel, state.project,
                                     edge_channels=edge_channels)

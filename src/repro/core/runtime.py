@@ -29,7 +29,7 @@ from repro.columnar import compute
 from repro.columnar.catalog import Catalog
 from repro.columnar.objectstore import ObjectStore
 from repro.columnar.table import ColumnTable, concat_tables
-from repro.core import defaults
+from repro.core import defaults, spans
 from repro.core.cache import ColumnarScanCache, IntermediateCache
 from repro.columnar.table import numeric_column
 from repro.core.channels import (DataTransport, ShardUnavailable, TableHandle,
@@ -68,7 +68,13 @@ class HandleUnavailable(RuntimeError):
 
 @dataclasses.dataclass
 class Event:
-    kind: str                 # plan|task_start|log|env_built|cache_hit|task_done|task_failed|speculative
+    # engine: plan, task_start, speculative, task_retry, input_lost,
+    # skew_split, worker_lost, deadline_exceeded, diagnostic; worker: log,
+    # cache_hit, stream_chunk, gather, combine, sample, partition,
+    # shuffle_merge, materialized, task_done; both: spans (a list of span
+    # records, core/spans.py: a worker's just before its task_done, the
+    # engine's for planning and for each task's wait)
+    kind: str
     task_id: str
     worker: str
     payload: Dict
@@ -105,7 +111,11 @@ class Client:
             cb(event)
         if self.verbose:
             p = event.payload
-            line = p.get("line") or ", ".join(f"{k}={v}" for k, v in p.items())
+            if event.kind == "spans":
+                line = f"{len(p['spans'])} spans"
+            else:
+                line = p.get("line") or ", ".join(f"{k}={v}"
+                                                  for k, v in p.items())
             print(f"[{event.worker or 'cp'}] {event.kind} {event.task_id} {line}",
                   file=sys.stderr)
 
@@ -215,40 +225,59 @@ class Worker:
                 edge_channels: Optional[Dict[str, str]] = None) -> TableHandle:
         """Run one task. `handles` is the run's synchronized HandleMap (or a
         plain dict in tests); `edge_channels` maps parent task id -> transfer
-        channel, bound by the engine at dispatch time from actual placement."""
+        channel, bound by the engine at dispatch time from actual placement.
+        The task's spans (core/spans.py) go out as one `spans` event just
+        before its `task_done`, whose seconds are the `task` span's."""
         self._check_alive()
-        t0 = time.perf_counter()
+        with spans.recording(plan.run_id, task.task_id, "task",
+                             kind=_task_kind(task)):
+            return self._execute(plan, task, handles, client, put_channel,
+                                 project, edge_channels or {})
+
+    def _task_done(self, client: Client, task, payload: Dict) -> None:
+        """Close the task's span, emit its spans, then `task_done` with the
+        span's wall seconds."""
+        seconds = spans.finish(rows=payload["rows"], bytes=payload["bytes"])
+        client.emit(Event("spans", task.task_id, self.worker_id,
+                          {"spans": spans.current().drain()}))
+        payload["seconds"] = round(seconds, 6)
+        client.emit(Event("task_done", task.task_id, self.worker_id, payload))
+
+    def _execute(self, plan: PhysicalPlan, task, handles, client: Client,
+                 put_channel: str, project: Optional["Project"],
+                 edge_channels: Dict[str, str]) -> TableHandle:
         if isinstance(task, ShuffleWriteTask):
             # publishes its own partition-addressed handle: P individually
             # fetchable slices, not one table — bypass the generic put
             parts = self._run_shuffle_write(plan, task, handles, client,
-                                            edge_channels or {})
+                                            edge_channels)
             self._check_alive()
-            handle = self.transport.put_shuffle(
-                f"{plan.run_id}:{task.task_id}", parts, put_channel)
-            client.emit(Event("task_done", task.task_id, self.worker_id,
-                              {"rows": handle.num_rows,
-                               "bytes": handle.nbytes,
-                               "seconds": round(time.perf_counter() - t0, 6),
-                               "channel": "shuffle",
-                               # per-partition byte histogram: the engine's
-                               # skew detector reads these off the handle,
-                               # the event is for observability/tests
-                               "partition_bytes": [p.nbytes
-                                                   for p in handle.parts]}))
+            with spans.span("task.output", channel="shuffle") as out:
+                handle = self.transport.put_shuffle(
+                    f"{plan.run_id}:{task.task_id}", parts, put_channel)
+                out.set(rows=handle.num_rows, bytes=handle.nbytes)
+            self._task_done(client, task,
+                            {"rows": handle.num_rows,
+                             "bytes": handle.nbytes,
+                             "channel": "shuffle",
+                             # per-partition byte histogram: the engine's
+                             # skew detector reads these off the handle,
+                             # the event is for observability/tests
+                             "partition_bytes": [p.nbytes
+                                                 for p in handle.parts]})
             return handle
         if plan.chunk_rows > 0 and isinstance(task, ScanTask) \
                 and task.streams_output:
             # streamed producers publish chunk-by-chunk and emit their own
             # task_done — consumers with a stream edge dispatch on the
             # first chunk instead of waiting for this return
-            return self._run_scan_stream(plan, task, client, put_channel, t0)
+            return self._run_scan_stream(plan, task, client, put_channel)
         if plan.chunk_rows > 0 and isinstance(task, FunctionTask) \
                 and (task.streams_output or task.stream_param) \
                 and not task.materialize:
             return self._run_function_stream(plan, task, handles, client,
-                                             project, edge_channels or {},
-                                             put_channel, t0)
+                                             project, edge_channels,
+                                             put_channel)
         if isinstance(task, ScanTask):
             table = self._run_scan(task, client)
         elif isinstance(task, GatherTask):
@@ -263,28 +292,30 @@ class Worker:
             table = self._run_shuffle_merge(plan, task, handles, client)
         else:
             table = self._run_function(plan, task, handles, client, project,
-                                       edge_channels or {})
+                                       edge_channels)
         self._check_alive()
         # run-scoped key: concurrent runs share the fleet, so bare task ids
         # would collide in the transport's table store
-        handle = self.transport.put(f"{plan.run_id}:{task.task_id}", table,
-                                    put_channel)
-        client.emit(Event("task_done", task.task_id, self.worker_id,
-                          {"rows": table.num_rows, "bytes": table.nbytes,
-                           "seconds": round(time.perf_counter() - t0, 6),
-                           "channel": put_channel}))
+        with spans.span("task.output", rows=table.num_rows,
+                        bytes=table.nbytes, channel=put_channel):
+            handle = self.transport.put(f"{plan.run_id}:{task.task_id}",
+                                        table, put_channel)
+        self._task_done(client, task,
+                        {"rows": table.num_rows, "bytes": table.nbytes,
+                         "channel": put_channel})
         return handle
 
     def _run_scan(self, task: ScanTask, client: Client) -> ColumnTable:
         snap = self.catalog.get_snapshot(task.snapshot_id)
         cols = list(task.columns) if task.columns else None
-        before = dict(self.scan_cache.stats)
-        table = self.scan_cache.read_snapshot(snap, cols, file_keys=task.files)
-        after = self.scan_cache.stats
-        client.emit(Event("cache_probe", task.task_id, self.worker_id,
-                          {"kind": "scan",
-                           "hits": after["hits"] - before["hits"],
-                           "misses": after["misses"] - before["misses"]}))
+        with spans.span("task.scan") as sp:
+            before = dict(self.scan_cache.stats)
+            table = self.scan_cache.read_snapshot(snap, cols,
+                                                  file_keys=task.files)
+            after = self.scan_cache.stats
+            sp.set(rows=table.num_rows, bytes=table.nbytes,
+                   hits=after["hits"] - before["hits"],
+                   misses=after["misses"] - before["misses"])
         return table
 
     # -- streamed execution (chunked data plane) ----------------------------
@@ -302,41 +333,41 @@ class Worker:
             yield from compute.iter_table_chunks(part, chunk_rows)
 
     def _run_scan_stream(self, plan: PhysicalPlan, task: ScanTask,
-                         client: Client, put_channel: str,
-                         t0: float) -> TableHandle:
+                         client: Client, put_channel: str) -> TableHandle:
         """Streamed scan: publish the snapshot as fixed-size row chunks
         under one handle. Each chunk lands in the transport the moment its
         file slice is read; the engine dispatches stream-capable consumers
         on the first `stream_chunk` event instead of on task_done."""
         snap = self.catalog.get_snapshot(task.snapshot_id)
         cols = list(task.columns) if task.columns else None
-        before = dict(self.scan_cache.stats)
         key = f"{plan.run_id}:{task.task_id}"
-        writer = self.transport.open_stream(key, put_channel)
         n = 0
-        try:
-            for chunk in self._scan_chunks(snap, cols, task, plan.chunk_rows):
-                self._check_alive()
-                writer.append(chunk)
-                client.emit(Event("stream_chunk", task.task_id,
-                                  self.worker_id,
-                                  {"chunk": n, "key": key,
-                                   "location": writer.location,
-                                   "rows": chunk.num_rows}))
-                n += 1
-            handle = writer.finish()
-        except BaseException:
-            writer.abort()
-            raise
-        after = self.scan_cache.stats
-        client.emit(Event("cache_probe", task.task_id, self.worker_id,
-                          {"kind": "scan",
-                           "hits": after["hits"] - before["hits"],
-                           "misses": after["misses"] - before["misses"]}))
-        client.emit(Event("task_done", task.task_id, self.worker_id,
-                          {"rows": handle.num_rows, "bytes": handle.nbytes,
-                           "seconds": round(time.perf_counter() - t0, 6),
-                           "channel": "stream", "chunks": n}))
+        # the scan publishes as it reads: the span holds both
+        with spans.span("task.scan", channel="stream") as sp:
+            before = dict(self.scan_cache.stats)
+            writer = self.transport.open_stream(key, put_channel)
+            try:
+                for chunk in self._scan_chunks(snap, cols, task,
+                                               plan.chunk_rows):
+                    self._check_alive()
+                    writer.append(chunk)
+                    client.emit(Event("stream_chunk", task.task_id,
+                                      self.worker_id,
+                                      {"chunk": n, "key": key,
+                                       "location": writer.location,
+                                       "rows": chunk.num_rows}))
+                    n += 1
+                handle = writer.finish()
+            except BaseException:
+                writer.abort()
+                raise
+            after = self.scan_cache.stats
+            sp.set(rows=handle.num_rows, bytes=handle.nbytes,
+                   hits=after["hits"] - before["hits"],
+                   misses=after["misses"] - before["misses"])
+        self._task_done(client, task,
+                        {"rows": handle.num_rows, "bytes": handle.nbytes,
+                         "channel": "stream", "chunks": n})
         return handle
 
     def _fetch_parts(self, plan: PhysicalPlan, task, handles,
@@ -357,15 +388,22 @@ class Worker:
         phandle = partitioned_handle(f"{plan.run_id}:{task.task_id}",
                                      [h for _, h in part_handles])
         n_local = sum(self.transport.has_local(h.key) for _, h in part_handles)
-        try:
-            if as_parts:
-                result = self.transport.get_parts(phandle, columns=columns)
-            else:
-                result = self.transport.get(phandle, columns=columns)
-        except ShardUnavailable as e:
-            lost = next((tid for tid, h in part_handles if h.key == e.key),
-                        task.inputs[0].parent_task)
-            raise HandleUnavailable(lost) from e
+        with spans.span("task.input", channel="parts",
+                        parts=len(part_handles), local=n_local) as sp:
+            try:
+                if as_parts:
+                    result = self.transport.get_parts(phandle,
+                                                      columns=columns)
+                else:
+                    result = self.transport.get(phandle, columns=columns)
+            except ShardUnavailable as e:
+                lost = next((tid for tid, h in part_handles
+                             if h.key == e.key),
+                            task.inputs[0].parent_task)
+                raise HandleUnavailable(lost) from e
+            tables = result if as_parts else [result]
+            sp.set(rows=sum(t.num_rows for t in tables),
+                   bytes=sum(t.nbytes for t in tables))
         return result, len(part_handles), n_local
 
     def _run_gather(self, plan: PhysicalPlan, task: GatherTask,
@@ -430,19 +468,26 @@ class Worker:
                                             if pred else []):
                 if c not in need:
                     need.append(c)
-        try:
-            table = self.transport.get(handle, columns=need, via=via)
-        except (OSError, ConnectionError, KeyError) as e:
-            raise HandleUnavailable(edge.parent_task) from e
-        if pred is not None:
-            table = compute.filter_table(table, pred)
-        if edge.ref.columns is not None:
-            # strict on the declared columns (a typo must raise, not silently
-            # vanish), lenient on system extras like a sample's sort key
-            keep = list(edge.ref.columns)
-            keep += [c for c in extra_columns
-                     if c not in keep and c in table.column_names]
-            table = table.project(keep)
+        with spans.span("task.input", channel=via or handle.channel) as sp:
+            try:
+                table = self.transport.get(handle, columns=need, via=via)
+            except (OSError, ConnectionError, KeyError) as e:
+                raise HandleUnavailable(edge.parent_task) from e
+            if pred is not None or edge.ref.columns is not None:
+                with spans.span("input.filter",
+                                rows_in=table.num_rows) as fsp:
+                    if pred is not None:
+                        table = compute.filter_table(table, pred)
+                    if edge.ref.columns is not None:
+                        # strict on the declared columns (a typo must
+                        # raise, not silently vanish), lenient on system
+                        # extras like a sample's sort key
+                        keep = list(edge.ref.columns)
+                        keep += [c for c in extra_columns
+                                 if c not in keep and c in table.column_names]
+                        table = table.project(keep)
+                    fsp.set(rows_out=table.num_rows)
+            sp.set(rows=table.num_rows, bytes=table.nbytes)
         return table
 
     def _edge_chunks(self, plan: PhysicalPlan, edge, handles,
@@ -465,17 +510,34 @@ class Worker:
             for c in (pred.referenced_columns() if pred else []):
                 if c not in need:
                     need.append(c)
+        channel = via if handle.channel not in ("stream", "chunked") \
+            else handle.channel
         try:
             if handle.channel in ("stream", "chunked"):
                 chunks = self.transport.get_stream(handle, columns=need)
             else:
-                whole = self.transport.get(handle, columns=need, via=via)
+                with spans.span("task.input", channel=channel):
+                    whole = self.transport.get(handle, columns=need, via=via)
                 chunks = compute.iter_table_chunks(whole, plan.chunk_rows)
-            for chunk in chunks:
-                if pred is not None:
-                    chunk = compute.filter_table(chunk, pred)
-                if edge.ref.columns is not None:
-                    chunk = chunk.project(list(edge.ref.columns))
+            chunks = iter(chunks)
+            while True:
+                # one span per chunk, never across the yield: the consumer's
+                # work on a chunk is not input delivery. A live stream's
+                # next() waits for the producer's next chunk.
+                with spans.span("task.input", channel=channel) as sp:
+                    chunk = next(chunks, None)
+                    if chunk is None:
+                        break
+                    if pred is not None or edge.ref.columns is not None:
+                        with spans.span("input.filter",
+                                        rows_in=chunk.num_rows) as fsp:
+                            if pred is not None:
+                                chunk = compute.filter_table(chunk, pred)
+                            if edge.ref.columns is not None:
+                                chunk = chunk.project(
+                                    list(edge.ref.columns))
+                            fsp.set(rows_out=chunk.num_rows)
+                    sp.set(rows=chunk.num_rows, bytes=chunk.nbytes)
                 yield chunk
         except (ShardUnavailable, OSError, ConnectionError, KeyError) as e:
             raise HandleUnavailable(edge.parent_task) from e
@@ -484,7 +546,7 @@ class Worker:
                              handles, client: Client,
                              project: Optional["Project"],
                              edge_channels: Dict[str, str],
-                             put_channel: str, t0: float) -> TableHandle:
+                             put_channel: str) -> TableHandle:
         """Streamed function execution (plan.chunk_rows > 0). Two shapes,
         both consuming the stream edge chunk-by-chunk:
 
@@ -503,12 +565,12 @@ class Worker:
         if cached is not None:
             client.emit(Event("cache_hit", task.task_id, self.worker_id,
                               {"cache_key": task.cache_key}))
-            handle = self.transport.put(key, cached, put_channel)
-            client.emit(Event("task_done", task.task_id, self.worker_id,
-                              {"rows": cached.num_rows,
-                               "bytes": cached.nbytes,
-                               "seconds": round(time.perf_counter() - t0, 6),
-                               "channel": put_channel}))
+            with spans.span("task.output", rows=cached.num_rows,
+                            bytes=cached.nbytes, channel=put_channel):
+                handle = self.transport.put(key, cached, put_channel)
+            self._task_done(client, task,
+                            {"rows": cached.num_rows, "bytes": cached.nbytes,
+                             "channel": put_channel})
             return handle
         from repro.api import default_project
         project = project or default_project()
@@ -541,11 +603,7 @@ class Worker:
         in_via = (edge_channels.get(stream_edge.parent_task)
                   or stream_edge.channel or "zerocopy")
         in_chunks = self._edge_chunks(plan, stream_edge, handles, via=in_via)
-        report = self.env_builder.build(spec.env, fresh=True)
-        client.emit(Event("env_built", task.task_id, self.worker_id,
-                          {"env_id": report.env_id,
-                           "seconds": round(report.duration_s, 6),
-                           "cache_hit": report.cache_hit}))
+        report = self._build_env(spec)
         emit_log = lambda line: client.emit(Event("log", task.task_id,
                                                   self.worker_id,
                                                   {"line": line}))
@@ -557,7 +615,8 @@ class Worker:
             # input iterator pulls the next chunk must keep propagating
             # for per-shard recovery
             try:
-                with router.route(emit_log):
+                with spans.span("task.user", rows=chunk.num_rows), \
+                        router.route(emit_log):
                     out = fn(**{stream_edge.param: chunk}, **kwargs)
             except Exception as e:  # noqa: BLE001 — user code
                 raise TaskError(
@@ -575,12 +634,14 @@ class Worker:
                     for chunk in in_chunks:
                         self._check_alive()
                         out = call_chunk(chunk)
-                        writer.append(out)
-                        client.emit(Event("stream_chunk", task.task_id,
-                                          self.worker_id,
-                                          {"chunk": n, "key": key,
-                                           "location": writer.location,
-                                           "rows": out.num_rows}))
+                        with spans.span("task.output", rows=out.num_rows,
+                                        bytes=out.nbytes, channel="stream"):
+                            writer.append(out)
+                            client.emit(Event("stream_chunk", task.task_id,
+                                              self.worker_id,
+                                              {"chunk": n, "key": key,
+                                               "location": writer.location,
+                                               "rows": out.num_rows}))
                         n += 1
                         if cache_parts is not None:
                             cache_bytes += out.nbytes
@@ -588,43 +649,46 @@ class Worker:
                                 cache_parts.append(out)
                             else:
                                 cache_parts = None  # too big: stream-only
-                    handle = writer.finish()
+                    with spans.span("task.output", phase="seal"):
+                        handle = writer.finish()
                 except BaseException:
                     writer.abort()
                     raise
                 if cache_parts is not None:
-                    self.result_cache.put(task.cache_key,
-                                          concat_tables(cache_parts))
-                client.emit(Event("task_done", task.task_id, self.worker_id,
-                                  {"rows": handle.num_rows,
-                                   "bytes": handle.nbytes,
-                                   "seconds": round(
-                                       time.perf_counter() - t0, 6),
-                                   "channel": "stream", "chunks": n}))
+                    with spans.span("task.output", phase="cache"):
+                        self.result_cache.put(task.cache_key,
+                                              concat_tables(cache_parts))
+                self._task_done(client, task,
+                                {"rows": handle.num_rows,
+                                 "bytes": handle.nbytes,
+                                 "channel": "stream", "chunks": n})
                 return handle
             # partial fold: per-chunk states, merged once (one combine
             # point keeps float accumulation order deterministic)
             states = [call_chunk(chunk) for chunk in in_chunks]
             self._check_alive()
             try:
-                merged = compute.fold_partial_states(states,
-                                                     contract.merge_states)
+                with spans.span("task.user", phase="merge",
+                                states=len(states)):
+                    merged = compute.fold_partial_states(
+                        states, contract.merge_states)
             except Exception as e:  # noqa: BLE001 — contract code
                 raise TaskError(f"{task.name} (state merge): "
                                 f"{type(e).__name__}: {e}\n"
                                 f"{traceback.format_exc()}") from e
-            merged = _coerce_output(task.name, merged)
-            merged = self.result_cache.put(task.cache_key, merged)
-            handle = self.transport.put(key, merged, put_channel)
-            client.emit(Event("task_done", task.task_id, self.worker_id,
-                              {"rows": merged.num_rows,
-                               "bytes": merged.nbytes,
-                               "seconds": round(time.perf_counter() - t0, 6),
-                               "channel": put_channel,
-                               "chunks": len(states)}))
+            with spans.span("task.output", channel=put_channel) as sp:
+                merged = _coerce_output(task.name, merged)
+                merged = self.result_cache.put(task.cache_key, merged)
+                handle = self.transport.put(key, merged, put_channel)
+                sp.set(rows=merged.num_rows, bytes=merged.nbytes)
+            self._task_done(client, task,
+                            {"rows": merged.num_rows,
+                             "bytes": merged.nbytes,
+                             "channel": put_channel,
+                             "chunks": len(states)})
             return handle
         finally:
-            self.env_builder.destroy(report)  # truly ephemeral
+            self._destroy_env(report)  # truly ephemeral
 
     # -- partition exchange (shuffle) ---------------------------------------
     def _run_shuffle_write(self, plan: PhysicalPlan, task: ShuffleWriteTask,
@@ -789,11 +853,7 @@ class Worker:
         task asks. Inputs must already be resolved: only `call` itself is
         wrapped as user error (HandleUnavailable has to keep propagating
         for per-shard recovery)."""
-        report = self.env_builder.build(spec.env, fresh=True)
-        client.emit(Event("env_built", task.task_id, self.worker_id,
-                          {"env_id": report.env_id,
-                           "seconds": round(report.duration_s, 6),
-                           "cache_hit": report.cache_hit}))
+        report = self._build_env(spec)
         emit_log = lambda line: client.emit(Event("log", task.task_id,
                                                   self.worker_id,
                                                   {"line": line}))
@@ -801,22 +861,35 @@ class Worker:
         # between phases; production never re-wraps
         router = _StdoutRouter.install()
         try:
-            with router.route(emit_log):
+            with spans.span("task.user"), router.route(emit_log):
                 out = call()
         except Exception as e:  # noqa: BLE001 — user code
             raise TaskError(f"{label}: {type(e).__name__}: {e}\n"
                             f"{traceback.format_exc()}") from e
         finally:
-            self.env_builder.destroy(report)  # truly ephemeral
-        table = _coerce_output(task.name, out)
-        table = self.result_cache.put(task.cache_key, table)
-        if task.materialize:
-            snap = self.catalog.write_table(task.name, table,
-                                            branch=plan.branch,
-                                            message=f"run {plan.run_id}")
-            client.emit(Event("materialized", task.task_id, self.worker_id,
-                              {"snapshot": snap.snapshot_id}))
+            self._destroy_env(report)  # truly ephemeral
+        with spans.span("task.output", materialize=task.materialize) as sp:
+            table = _coerce_output(task.name, out)
+            table = self.result_cache.put(task.cache_key, table)
+            sp.set(rows=table.num_rows, bytes=table.nbytes)
+            if task.materialize:
+                snap = self.catalog.write_table(task.name, table,
+                                                branch=plan.branch,
+                                                message=f"run {plan.run_id}")
+                client.emit(Event("materialized", task.task_id,
+                                  self.worker_id,
+                                  {"snapshot": snap.snapshot_id}))
         return table
+
+    def _build_env(self, spec):
+        with spans.span("task.env", phase="build") as sp:
+            report = self.env_builder.build(spec.env, fresh=True)
+            sp.set(env_id=report.env_id, cache_hit=report.cache_hit)
+        return report
+
+    def _destroy_env(self, report) -> None:
+        with spans.span("task.env", phase="destroy"):
+            self.env_builder.destroy(report)
 
     def _run_function(self, plan: PhysicalPlan, task: FunctionTask,
                       handles, client: Client,
@@ -851,6 +924,21 @@ class Worker:
         return self._invoke_user_code(plan, task, spec,
                                       lambda: fn(**kwargs), client,
                                       label=task.name)
+
+
+_TASK_KINDS = ((ScanTask, "scan"), (GatherTask, "gather"),
+               (CombineTask, "combine"), (ShuffleWriteTask, "shuffle_write"),
+               (ShuffleSampleTask, "sample"), (PartitionTask, "partition"),
+               (ShuffleMergeTask, "shuffle_merge"))
+
+
+def _task_kind(task) -> str:
+    """The `kind` of a task's span: scan, function, partial, combine, ..."""
+    for cls, kind in _TASK_KINDS:
+        if isinstance(task, cls):
+            return kind
+    return "partial" if getattr(task, "agg_phase", "") == "partial" \
+        else "function"
 
 
 def _coerce_output(name: str, out) -> ColumnTable:
@@ -995,46 +1083,53 @@ def submit_run(project: "Project", cluster,
     if validate not in ("off", "warn", "strict"):
         raise ValueError(f"validate must be 'off', 'warn' or 'strict', "
                          f"got {validate!r}")
-    if validate != "off":
-        from repro.analysis import check_project
+    client = client if client is not None else Client()
+    # planning is the run's first span: project -> logical -> physical
+    with spans.recording("", "", "run.plan") as rec:
+        if validate != "off":
+            from repro.analysis import check_project
 
-        report = check_project(project, catalog=cluster.catalog,
-                               branch=branch, targets=targets)
-        if client is not None:
+            report = check_project(project, catalog=cluster.catalog,
+                                   branch=branch, targets=targets)
             for d in report.diagnostics:
                 client.emit(Event(kind="diagnostic", task_id="", worker="",
                                   payload={"line": d.render(),
                                            "code": d.code,
                                            "severity": d.severity,
                                            "model": d.model}))
-        if validate == "strict":
-            report.raise_first()
-    logical = build_logical_plan(project, targets)
-    planner_kw = {}
-    if shard_threshold_bytes is not None:
-        planner_kw["shard_threshold_bytes"] = shard_threshold_bytes
-    if max_shards is not None:
-        planner_kw["max_shards"] = max_shards
-    # stream=False forces the materialized data plane (whole-table handles);
-    # chunk_rows overrides defaults.STREAM_CHUNK_ROWS for this run
-    planner_kw["stream"] = stream
-    if chunk_rows is not None:
-        planner_kw["chunk_rows"] = chunk_rows
-    if lineage_pushdown:
-        # pass-1 column lineage: proven read sets for edges that declared
-        # no columns= hint narrow scans and gathers. Inference is
-        # conservative (unprovable -> read everything), and any analyzer
-        # failure falls back to the declared-union behavior rather than
-        # blocking the run.
-        try:
-            from repro.analysis.schema import edge_read_columns
+            if validate == "strict":
+                report.raise_first()
+        logical = build_logical_plan(project, targets)
+        planner_kw = {}
+        if shard_threshold_bytes is not None:
+            planner_kw["shard_threshold_bytes"] = shard_threshold_bytes
+        if max_shards is not None:
+            planner_kw["max_shards"] = max_shards
+        # stream=False forces the materialized data plane (whole-table
+        # handles); chunk_rows overrides defaults.STREAM_CHUNK_ROWS for this
+        # run
+        planner_kw["stream"] = stream
+        if chunk_rows is not None:
+            planner_kw["chunk_rows"] = chunk_rows
+        if lineage_pushdown:
+            # pass-1 column lineage: proven read sets for edges that
+            # declared no columns= hint narrow scans and gathers. Inference
+            # is conservative (unprovable -> read everything), and any
+            # analyzer failure falls back to the declared-union behavior
+            # rather than blocking the run.
+            try:
+                from repro.analysis.schema import edge_read_columns
 
-            planner_kw["edge_columns"] = edge_read_columns(project, targets)
-        except Exception:
-            pass
-    planner = Planner(cluster.catalog, cluster.profiles(),
-                      force_channel=force_channel, **planner_kw)
-    plan = planner.plan(logical, branch=branch, run_id=run_id)
+                planner_kw["edge_columns"] = edge_read_columns(project,
+                                                               targets)
+            except Exception:
+                pass
+        planner = Planner(cluster.catalog, cluster.profiles(),
+                          force_channel=force_channel, **planner_kw)
+        plan = planner.plan(logical, branch=branch, run_id=run_id)
+        rec.root.set(tasks=len(plan.order))
+    rec.run, rec.task = plan.run_id, plan.plan_id
+    client.emit(Event("spans", plan.plan_id, "", {"spans": rec.drain()}))
     return cluster.engine().submit(plan, project, client=client,
                                    journal_path=journal_path,
                                    priority=priority, deadline_s=deadline_s,

@@ -50,6 +50,7 @@ def block_counts(mask: jax.Array, block_n: int, interpret: bool = False
         out_specs=pl.BlockSpec((1, _LANES), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((1, nb * _LANES), jnp.int32),
         interpret=interpret,
+        name="filter_block_counts",
     )(mask)
     return out.reshape(nb, _LANES)[:, 0]
 
@@ -91,5 +92,6 @@ def block_compact(mask: jax.Array, block_n: int, interpret: bool = False
         out_specs=pl.BlockSpec((1, block_n), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
+        name="filter_block_compact",
     )(mask)
     return out.reshape(n // block_n, block_n)

@@ -116,4 +116,5 @@ def flash_attention_3d(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),     # running denominator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -95,6 +95,7 @@ def combine_pallas(parts: jax.Array, fn: str = "sum", block_p: int = 8,
         out_shape=jax.ShapeDtypeStruct((g,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((g,), jnp.float32)],
         interpret=interpret,
+        name="groupby_combine",
     )(parts)
 
 
@@ -118,4 +119,5 @@ def groupby_pallas(values: jax.Array, codes: jax.Array, n_groups: int,
         out_shape=jax.ShapeDtypeStruct((n_groups,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n_groups,), jnp.float32)],
         interpret=interpret,
+        name="groupby_segment",
     )(values, codes)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.kernels import filter_compact as _fc
 from repro.kernels import flash_attention as _fa
 from repro.kernels import groupby_agg as _gb
@@ -170,6 +171,8 @@ def compact(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
 # jitted wrapper compiles once per input shape. These entry points pad the
 # rows to a power of two of at least ROW_BLOCK, with rows that change no
 # result, so a whole scan compiles a few programs rather than one per chunk.
+# Each call is one `op.kernel` span (core/spans.py): the host pad, the copy
+# to the device, dispatch, the wait for the result and the copy back.
 
 
 def _bucket(n: int) -> int:
@@ -185,9 +188,11 @@ def _pad_rows(x: np.ndarray, rows: int, fill) -> np.ndarray:
 def compact_indices(mask) -> np.ndarray:
     """The surviving indices of a host boolean mask, ascending."""
     mask = np.asarray(mask, bool)
-    idx, count = compact(jnp.asarray(_pad_rows(mask, _bucket(mask.size),
-                                               False)))
-    return np.asarray(idx)[: int(count)]
+    rows = _bucket(mask.size)
+    with span("op.kernel", kernel="compact", padded_rows=rows,
+              bytes_in=4 * rows, bytes_out=4 * rows, compiles=0):
+        idx, count = compact(jnp.asarray(_pad_rows(mask, rows, False)))
+        return np.asarray(idx)[: int(count)]
 
 
 def groupby_aggregate_rows(values, codes, n_groups: int,
@@ -198,7 +203,11 @@ def groupby_aggregate_rows(values, codes, n_groups: int,
     _check_groups(n_groups)
     ng_pad = _lane_pad(n_groups)
     rows = _bucket(len(values))
-    vals = _pad_rows(np.asarray(values, np.float32), rows, 0.0)
-    cds = _pad_rows(np.asarray(codes, np.int32), rows, ng_pad)
-    out = groupby_aggregate(jnp.asarray(vals), jnp.asarray(cds), ng_pad, fn)
-    return np.asarray(out)[:n_groups]
+    with span("op.kernel", kernel="groupby_aggregate", fn=fn,
+              padded_rows=rows, bytes_in=8 * rows, bytes_out=4 * ng_pad,
+              compiles=0):
+        vals = _pad_rows(np.asarray(values, np.float32), rows, 0.0)
+        cds = _pad_rows(np.asarray(codes, np.int32), rows, ng_pad)
+        out = groupby_aggregate(jnp.asarray(vals), jnp.asarray(cds), ng_pad,
+                                fn)
+        return np.asarray(out)[:n_groups]

@@ -129,6 +129,26 @@ def test_remote_sharded_run_matches_local(rcluster, cat, tmp_path,
     assert any("doubling" in line for line in client.logs())
 
 
+def test_remote_workers_ship_their_spans(rcluster, project_spec):
+    """A worker process records its task's spans and sends them inside the
+    events it already streams back, ahead of the task's task_done."""
+    client = Client()
+    res = execute_run(load_project_spec(project_spec), cluster=rcluster,
+                      client=client, shard_threshold_bytes=1, max_shards=2)
+    remote = {w.worker_id for w in rcluster.workers.values()}
+    for tid in res.plan.order:
+        roots = [s for e in client.of_kind("spans") if e.task_id == tid
+                 for s in e.payload["spans"] if s["name"] == "task"]
+        done = [e for e in client.of_kind("task_done") if e.task_id == tid]
+        assert len(roots) == len(done) == 1, tid
+        assert done[0].worker in remote
+        assert roots[0]["run"] == res.run_id and roots[0]["task"] == tid
+        assert abs(roots[0]["wall"] - done[0].payload["seconds"]) < 1e-3
+    users = [s for e in client.of_kind("spans") for s in e.payload["spans"]
+             if s["name"] == "task.user"]
+    assert users and all(s["cpu"] is not None for s in users)
+
+
 def test_describe_heartbeat_and_cancel_rpcs(rcluster):
     w = next(iter(rcluster.workers.values()))
     hb = w.heartbeat()
