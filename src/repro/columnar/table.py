@@ -130,6 +130,13 @@ class Column:
 
     # -- kernels used by compute (gather copies; slice views) -----------------
     def take(self, indices: np.ndarray) -> "Column":
+        """Gather rows by integer index, with numpy fancy-indexing meaning.
+
+        utf8 gathers every selected row's bytes in one vectorised pass:
+        each output byte's source position is its row's absolute start
+        offset (a `slice` need not start at byte 0) plus its place in the
+        row. The new offsets start at 0.
+        """
         indices = np.asarray(indices)
         validity = None
         if self.validity is not None:
@@ -139,10 +146,9 @@ class Column:
             lengths = (off[1:] - off[:-1])[indices]
             new_off = np.zeros(len(indices) + 1, dtype=np.int32)
             np.cumsum(lengths, out=new_off[1:])
-            new_data = np.empty(int(new_off[-1]), dtype=np.uint8)
-            for j, i in enumerate(indices):
-                new_data[new_off[j]:new_off[j + 1]] = self.data[off[i]:off[i + 1]]
-            return Column("utf8", new_data, new_off, validity)
+            shift = off[:-1][indices].astype(np.int64) - new_off[:-1]
+            positions = np.repeat(shift, lengths) + np.arange(int(new_off[-1]))
+            return Column("utf8", self.data[positions], new_off, validity)
         return Column(self.kind, self.data[indices], None, validity)
 
     def slice(self, start: int, length: int) -> "Column":

@@ -6,7 +6,8 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.columnar import ColumnTable, compute, utf8_column
-from repro.columnar.table import concat_tables, numeric_column
+from repro.columnar.table import (Column, concat_tables, numeric_column,
+                                   pack_validity)
 
 
 # -- strategies ----------------------------------------------------------------
@@ -131,3 +132,91 @@ def test_utf8_roundtrip_unicode():
     vals = ["héllo", "wörld", "日本語", ""]
     c = utf8_column(vals)
     assert list(c.to_numpy()) == vals
+
+
+# -- utf8 gather against a per-row reference -------------------------------------
+
+
+def _take_rowwise(col, indices):
+    """Plain per-row utf8 gather: the reference for `Column.take`."""
+    n = col.num_rows
+    rows = [int(i) + n if i < 0 else int(i) for i in indices]
+    off, mask = col.offsets, col.valid_mask()
+    chunks = [col.data[off[i]:off[i + 1]].tobytes() for i in rows]
+    offsets = np.zeros(len(rows) + 1, dtype=np.int32)
+    np.cumsum([len(b) for b in chunks], out=offsets[1:])
+    data = np.frombuffer(b"".join(chunks), dtype=np.uint8)
+    validity = (None if col.validity is None
+                else pack_validity(np.array([mask[i] for i in rows], bool)))
+    values = col.to_pylist()
+    return data, offsets, validity, [values[i] for i in rows]
+
+
+def _q1_flags(n=1000, seed=7):
+    # as the TPC-H Q1 configuration builds l_returnflag: one byte per row
+    codes = np.random.default_rng(seed).choice(
+        np.frombuffer(b"ANR", np.uint8), n)
+    return Column("utf8", codes, np.arange(n + 1))
+
+
+_UTF8_TAKE_CASES = {
+    "slice_offsets_not_at_zero": (
+        lambda: utf8_column(["ab", "cde", "", "f", "ghij", "kl"]).slice(2, 3),
+        [2, 0, 1, 1]),
+    "empty_indices": (lambda: utf8_column(["a", "bc"]), []),
+    "empty_strings": (lambda: utf8_column(["", "", "x", ""]), [0, 1, 3, 2]),
+    "multi_byte": (lambda: utf8_column(["日本語", "é", "a", "wörld"]),
+                   [0, 3, 2, 1, 0]),
+    "repeated_unsorted_negative": (
+        lambda: utf8_column(["aa", "b", "ccc", "dd"]), [3, -1, 0, 0, -4, 2, -2]),
+    "nulls": (lambda: utf8_column(["hi", None, "yo", None, "日"]),
+              [1, 2, 4, 3, 0, 1]),
+    "nulls_in_slice": (
+        lambda: utf8_column(["x", None, "yz", "", None, "w"]).slice(1, 4),
+        [3, 0, 2, -3]),
+    "q1_flags": (_q1_flags,
+                 np.nonzero(np.random.default_rng(3).random(1000) < 0.97)[0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UTF8_TAKE_CASES))
+def test_utf8_take_matches_rowwise_reference(case):
+    make, indices = _UTF8_TAKE_CASES[case]
+    col = make()
+    indices = np.asarray(indices, dtype=np.int64)
+    got = col.take(indices)
+    data, offsets, validity, values = _take_rowwise(col, indices)
+    assert got.data.dtype == np.uint8 and got.offsets.dtype == np.int32
+    np.testing.assert_array_equal(got.data, data)
+    np.testing.assert_array_equal(got.offsets, offsets)
+    if validity is None:
+        assert got.validity is None
+    else:
+        np.testing.assert_array_equal(got.validity, validity)
+    assert got.to_pylist() == values
+
+
+def test_filter_mixed_table_matches_rowwise_reference():
+    n = 500
+    rng = np.random.default_rng(11)
+    words = ["", "a", "bb", "日本語", None, "é"]
+    t = ColumnTable({
+        "x": numeric_column(rng.normal(size=n)),
+        "i": numeric_column(np.arange(n, dtype=np.int32)),
+        "flag": _q1_flags(n, seed=5),
+        "s": utf8_column([words[k] for k in rng.integers(0, len(words), n)]),
+    }).slice(17, 400)
+    mask = t.column("x").data > -0.5
+    got = t.filter(mask)
+    rows = np.nonzero(mask)[0]
+    np.testing.assert_array_equal(got.column("x").data, t.column("x").data[rows])
+    np.testing.assert_array_equal(got.column("i").data, t.column("i").data[rows])
+    for name in ("flag", "s"):
+        data, offsets, validity, values = _take_rowwise(t.column(name), rows)
+        c = got.column(name)
+        np.testing.assert_array_equal(c.data, data)
+        np.testing.assert_array_equal(c.offsets, offsets)
+        assert (c.validity is None) == (validity is None)
+        if validity is not None:
+            np.testing.assert_array_equal(c.validity, validity)
+        assert c.to_pylist() == values
