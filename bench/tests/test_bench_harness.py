@@ -13,25 +13,15 @@ import numpy as np
 import pytest
 
 from bench import harness
+from bench.tests import faults
 
 ROOT = harness.ROOT
-# each configuration at a size a test run holds (keyed by configuration)
-SMALL = {
-    "tpch_q1_sf1": {"rows": 6000, "rows_per_file": 1000,
-                    "shard_threshold_bytes": 1024},
-    "fig1_paper": {},
-}
-# the faults each configuration's timed path can have
-FAULTS = {
-    "tpch_q1_sf1": ["answer_altered", "half_left_out"],
-    "fig1_paper": ["answer_altered", "half_left_out", "rows_dropped"],
-}
 SEED = 2**31 + 12345     # larger than a signed 32-bit int
 
 
 # BENCHMARK.json and the cells that wait in bench/pending.json
 SPEC = harness.load_spec(pending=True)
-CELLS = {w["name"]: w["config"] for w in SPEC["workloads"]}
+CELLS = sorted(w["name"] for w in SPEC["workloads"])
 
 
 def _cell(name):
@@ -41,8 +31,9 @@ def _cell(name):
 def _run_cli(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
     env.update(env_extra or {})
+    workload = harness.load_spec()["workloads"][0]["name"]
     return subprocess.run(
-        [sys.executable, "bench/run.py", "--workload", "q1-sf1", "--seed",
+        [sys.executable, "bench/run.py", "--workload", workload, "--seed",
          str(SEED), "--seconds", "1", "--trace", "0"],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
 
@@ -74,16 +65,17 @@ def test_refuses_without_the_program(tmp_path):
     assert _no_result(proc.stdout)
 
 
-def _run(cell_name, seconds=1.5, trace=False):
+def _run(cell_name, seconds=1.5, trace=False, cell=None):
     import jax
 
-    cell = _cell(cell_name)
+    cell = cell or _cell(cell_name)
+    # each configuration at the size a test run holds
     return harness.run_cell(cell, SEED, seconds, trace, jax.devices(),
                             time.perf_counter(),
-                            overrides=SMALL[CELLS[cell_name]])
+                            overrides=cell.config["test_sizes"])
 
 
-@pytest.mark.parametrize("cell_name", sorted(CELLS))
+@pytest.mark.parametrize("cell_name", CELLS)
 def test_a_run_of_the_cell_is_correct(cell_name):
     res = _run(cell_name)
     assert res["correct"], res["checks"]
@@ -95,7 +87,7 @@ def test_a_run_of_the_cell_is_correct(cell_name):
         assert check["value"] <= check["limit"]
 
 
-@pytest.mark.parametrize("cell_name", sorted(CELLS))
+@pytest.mark.parametrize("cell_name", CELLS)
 def test_a_traced_run_reports_the_layers(cell_name):
     res = _run(cell_name, trace=True)
     assert res["correct"], res["checks"]
@@ -109,37 +101,24 @@ def test_a_traced_run_reports_the_layers(cell_name):
     assert "breakdown" in res
 
 
-def _plant(monkeypatch, fault):
-    """Break the timed path under the program's own entry points."""
-    from repro.kernels import ops
-
-    agg, compact = ops.groupby_aggregate_rows, ops.compact_indices
-    if fault == "answer_altered":
-        monkeypatch.setattr(ops, "groupby_aggregate_rows",
-                            lambda v, c, n, fn="sum": agg(v, c, n, fn)
-                            * (1 + 1e-3))
-    elif fault == "half_left_out":
-        def half(values, codes, n_groups, fn="sum"):
-            k = (len(values) + 1) // 2
-            out = agg(values[:k], codes[:k], n_groups, fn)
-            # the mean of the rows kept, scaled back up to the whole
-            return out * (len(values) / k)
-        monkeypatch.setattr(ops, "groupby_aggregate_rows", half)
-    elif fault == "rows_dropped":
-        monkeypatch.setattr(ops, "compact_indices",
-                            lambda mask: compact(mask)[::2])
-
-
+# the faults each configuration's timed path can have; one without the key
+# has none here, and fails test_configs by the key's name
 @pytest.mark.parametrize("cell_name,fault", [
-    (cell, fault) for cell in sorted(CELLS) for fault in FAULTS[CELLS[cell]]])
+    (cell, fault) for cell in CELLS
+    for fault in _cell(cell).config.get("faults", [])])
 def test_a_planted_fault_makes_the_run_incorrect(monkeypatch, cell_name,
                                                  fault):
-    _plant(monkeypatch, fault)
-    res = _run(cell_name)
+    # one cell plants and runs, so a planter of the configuration's own
+    # module breaks the module that the run drives
+    cell = _cell(cell_name)
+    plant = faults.planter(fault, cell.config_module)
+    assert plant is not None, f"no planter for fault {fault!r}"
+    plant(monkeypatch)
+    res = _run(cell_name, cell=cell)
     assert not res["correct"], res["checks"]
 
 
-@pytest.mark.parametrize("cell_name", sorted(CELLS))
+@pytest.mark.parametrize("cell_name", CELLS)
 def test_the_control_fails_the_comparison(monkeypatch, cell_name):
     """The reference in bfloat16, put in the program's place on the timed
     path, makes the harness's own check come out not correct."""

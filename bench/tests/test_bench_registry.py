@@ -6,10 +6,13 @@ import json
 import os
 import re
 import shutil
+import subprocess
+import sys
 
 import pytest
 
 from bench import harness
+from bench.tests import faults
 
 ROOT = harness.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -51,8 +54,24 @@ def test_configs(spec):
             assert NAME.match(key)
             # every cut is stated in the file with its reason
             assert key in config["reduced"], key
-        for key in ("source", "reduced", "assumed", "guarantees", "limits"):
-            assert key in config, key
+    # every configuration file, measured or pending, also says how the CPU
+    # tests run it: at which sizes, and with which faults planted
+    full = harness.load_spec(pending=True)
+    for c in full["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            config = json.load(f)
+        for key in ("source", "reduced", "assumed", "guarantees", "limits",
+                    "test_sizes", "faults"):
+            assert key in config, f"{c['file']} has no {key!r}"
+        # a misspelt size would leave a test at the full size
+        for key in config["test_sizes"]:
+            assert key in config, f"{c['file']}: test size {key!r} is no key"
+        assert config["faults"], f"{c['file']} names no fault"
+        cell = harness.Cell(next(w["name"] for w in full["workloads"]
+                                 if w["config"] == c["name"]), spec=full)
+        for fault in config["faults"]:
+            assert faults.planter(fault, cell.config_module), \
+                f"{c['file']}: no planter for fault {fault!r}"
 
 
 def test_workloads(spec):
@@ -148,25 +167,28 @@ def _digest(root):
     return out
 
 
-def test_new_pieces_are_taken_up_from_files_and_entries(tmp_path):
-    """A later change adds a configuration, a traffic mix and a per-layer
-    metric as new files and new entries, and edits no file."""
-    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
+def _add_a_cell(root):
+    """Copy the benchmark to `root` and add to the copy what a later change
+    adds: a configuration, a traffic mix, a per-layer metric and a cell,
+    as new files and new entries. Returns the copy's digest before, the
+    cell that was copied, the new configuration's stem and the end-to-end
+    metrics the new cell reports."""
+    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    before = _digest(tmp_path / "bench")
+    before = _digest(root / "bench")
     spec = harness.load_spec()
     old = spec["workloads"][0]
     cfg = next(c for c in spec["configs"] if c["name"] == old["config"])
     stem = os.path.splitext(cfg["file"])[0]
     for ext in (".json", ".py"):
-        shutil.copy(tmp_path / (stem + ext), tmp_path / (stem + "_copy" + ext))
-    traffic_dir = tmp_path / "bench" / "traffic"
+        shutil.copy(root / (stem + ext), root / (stem + "_copy" + ext))
+    traffic_dir = root / "bench" / "traffic"
     with open(traffic_dir / (old["traffic"] + ".json")) as f:
         traffic = json.load(f)
     traffic["note"] = "a new mix"
     with open(traffic_dir / "new_mix.json", "w") as f:
         json.dump(traffic, f)
-    with open(tmp_path / "bench" / "layers" / "answered.new.py", "w") as f:
+    with open(root / "bench" / "layers" / "answered.new.py", "w") as f:
         f.write("def read(run):\n"
                 "    return sum(o.done is not None for o in run.outcomes)\n")
     spec["configs"].append(dict(cfg, name=cfg["name"] + "_copy",
@@ -184,8 +206,15 @@ def test_new_pieces_are_taken_up_from_files_and_entries(tmp_path):
                               "better": "higher", "source": "host_clock",
                               "layer": "load generator", "moves": moves[0],
                               "workloads": ["new-cell"]})
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
+    with open(root / "BENCHMARK.json", "w") as f:
         json.dump(spec, f)
+    return before, old, stem, moves
+
+
+def test_new_pieces_are_taken_up_from_files_and_entries(tmp_path):
+    """A later change adds a configuration, a traffic mix and a per-layer
+    metric as new files and new entries, and edits no file."""
+    before, old, stem, moves = _add_a_cell(tmp_path)
 
     cell = harness.Cell("new-cell", root=str(tmp_path))
     assert cell.traffic["note"] == "a new mix"
@@ -199,3 +228,65 @@ def test_new_pieces_are_taken_up_from_files_and_entries(tmp_path):
     # the cells that were there resolve as before
     assert harness.Cell(old["name"], root=str(tmp_path)).traffic == \
         harness.Cell(old["name"]).traffic
+
+
+# a fault of the new configuration's own, planted from its own module
+OWN_FAULT = '''
+
+def plant_answer_doubled(monkeypatch):
+    from repro.kernels import ops
+
+    agg = ops.groupby_aggregate_rows
+    monkeypatch.setattr(ops, "groupby_aggregate_rows",
+                        lambda v, c, n, fn="sum": agg(v, c, n, fn) * 2)
+'''
+
+
+def _pytest_in(root, *args):
+    """pytest in the copy at `root`, on the CPU, with this checkout's
+    program; the outer run's pytest settings (an xdist worker's) left out."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(ROOT, "src"))
+    return subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         *args], cwd=root, env=env, capture_output=True, text=True,
+        timeout=300)
+
+
+def test_the_harness_tests_take_up_a_new_cell(tmp_path):
+    """The harness's CPU tests find a new cell's sizes and faults in its
+    configuration's files, collect its runs, faults and control, and run it
+    correct, with no file that was there edited."""
+    before, old, stem, _ = _add_a_cell(tmp_path)
+    with open(tmp_path / (stem + "_copy.json")) as f:
+        config = json.load(f)
+    config["faults"].append("answer_doubled")
+    with open(tmp_path / (stem + "_copy.json"), "w") as f:
+        json.dump(config, f)
+    with open(tmp_path / (stem + "_copy.py"), "a") as f:
+        f.write(OWN_FAULT)
+    module = "bench/tests/test_bench_harness.py"
+
+    listed = _pytest_in(tmp_path, "--collect-only", module)
+    assert listed.returncode == 0, listed.stdout + listed.stderr
+    ids = {line.split("::", 1)[1] for line in listed.stdout.splitlines()
+           if line.startswith(module + "::")}
+    want = {f"{test}[new-cell]" for test in (
+        "test_a_run_of_the_cell_is_correct",
+        "test_a_traced_run_reports_the_layers",
+        "test_the_control_fails_the_comparison")}
+    want |= {f"test_a_planted_fault_makes_the_run_incorrect[new-cell-{f}]"
+             for f in config["faults"]}
+    assert want <= ids, sorted(want - ids)
+    # the cells that were there keep their tests
+    assert any(i.endswith(f"[{old['name']}]") for i in ids)
+
+    ran = _pytest_in(
+        tmp_path, module + "::test_a_run_of_the_cell_is_correct[new-cell]",
+        module + "::test_a_planted_fault_makes_the_run_incorrect"
+        "[new-cell-answer_doubled]")
+    assert ran.returncode == 0, ran.stdout[-4000:] + ran.stderr[-4000:]
+    assert "2 passed" in ran.stdout, ran.stdout[-4000:]
+    after = _digest(tmp_path / "bench")
+    assert {k: v for k, v in after.items() if k in before} == before
