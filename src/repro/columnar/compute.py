@@ -34,7 +34,11 @@ def filter_table(table: ColumnTable, predicate: Union[str, Expr],
     expr = parse_predicate(predicate)
     if expr is None:
         return table
-    mask = np.asarray(expr.evaluate(table), dtype=bool)
+    # the predicate's mask, its compaction (an `op.kernel` span of its
+    # own) and the gather are sibling spans under the caller's
+    with span("op.mask", rows_in=table.num_rows,
+              columns=expr.referenced_columns()) as mask_span:
+        mask = np.asarray(expr.evaluate(table), dtype=bool)
     if backend == "jax":
         # Device path: mask+compact through the Pallas-backed op for numeric
         # columns; utf8 columns fall back to host gather.
@@ -46,8 +50,14 @@ def filter_table(table: ColumnTable, predicate: Union[str, Expr],
             idx = kops.compact_indices(mask)
         else:
             idx = np.nonzero(mask)[0]
-        return table.take(idx)
-    return table.filter(mask)
+        mask_span.set(rows_out=len(idx))
+        with span("op.gather", rows=len(idx)) as sp:
+            out = table.take(idx)
+            sp.set(bytes=out.nbytes)
+        return out
+    out = table.filter(mask)
+    mask_span.set(rows_out=out.num_rows)
+    return out
 
 
 def project(table: ColumnTable, columns: Sequence[str]) -> ColumnTable:

@@ -308,7 +308,7 @@ class Worker:
     def _run_scan(self, task: ScanTask, client: Client) -> ColumnTable:
         snap = self.catalog.get_snapshot(task.snapshot_id)
         cols = list(task.columns) if task.columns else None
-        with spans.span("task.scan") as sp:
+        with spans.span("task.scan", files=len(task.files)) as sp:
             before = dict(self.scan_cache.stats)
             table = self.scan_cache.read_snapshot(snap, cols,
                                                   file_keys=task.files)
@@ -343,7 +343,8 @@ class Worker:
         key = f"{plan.run_id}:{task.task_id}"
         n = 0
         # the scan publishes as it reads: the span holds both
-        with spans.span("task.scan", channel="stream") as sp:
+        with spans.span("task.scan", channel="stream",
+                        files=len(task.files)) as sp:
             before = dict(self.scan_cache.stats)
             writer = self.transport.open_stream(key, put_channel)
             try:

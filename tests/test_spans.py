@@ -59,6 +59,55 @@ def test_spans_outside_a_task_are_no_ops():
     assert spans.current() is None
 
 
+def _transactions():
+    return ColumnTable.from_pydict({
+        "id": np.arange(6, dtype=np.int64),
+        "usd": [1.0, 2.0, 3.0, 4.0, 5.0, 6.0],
+        "country": ["IT", "US", "FR", "JP", "IT", "GB"],
+    })
+
+
+def test_the_device_filter_opens_mask_kernel_and_gather_spans():
+    t = _transactions()
+    with spans.recording("r", "t", "task") as rec:
+        with spans.span("task.user"):
+            out = compute.filter_table(t, "country IN ('IT','FR')",
+                                       backend="jax")
+        spans.finish()
+        got = rec.drain()
+    assert out.column("id").to_numpy().tolist() == [0, 2, 4]
+    names = [s["name"] for s in got]
+    assert names == ["task", "task.user", "op.mask", "op.kernel",
+                     "op.gather"]
+    # siblings, in that order, under the caller's span
+    assert [s["parent"] for s in got[2:]] == [1, 1, 1]
+    assert got[2]["start"] <= got[3]["start"] <= got[4]["start"]
+    mask, kernel, gather = (s["attrs"] for s in got[2:])
+    assert mask == {"rows_in": 6, "rows_out": 3, "columns": ["country"]}
+    assert kernel["kernel"] == "compact"
+    assert gather == {"rows": 3, "bytes": out.nbytes}
+
+
+def test_the_host_filter_opens_a_mask_span_only():
+    t = _transactions()
+    with spans.recording("r", "t", "task") as rec:
+        out = compute.filter_table(t, "usd > 2.5")
+        spans.finish()
+        got = rec.drain()
+    assert out.num_rows == 4
+    assert [s["name"] for s in got] == ["task", "op.mask"]
+    assert got[1]["attrs"] == {"rows_in": 6, "rows_out": 4,
+                               "columns": ["usd"]}
+
+
+def test_the_filter_records_nothing_outside_a_task():
+    assert spans.current() is None
+    out = compute.filter_table(_transactions(), "country IN ('GB')",
+                               backend="jax")
+    assert out.column("id").to_numpy().tolist() == [5]
+    assert spans.current() is None
+
+
 def test_compiles_counts_on_the_first_call_of_a_shape_only():
     from repro.kernels import ops
 
@@ -212,6 +261,17 @@ def test_kernel_and_key_spans_sit_inside_the_operators(sharded_run):
                 assert group[s["parent"]]["name"] == "task.input"
             if s["name"] == "task.scan":
                 assert s["attrs"]["hits"] + s["attrs"]["misses"] > 0
+
+
+def test_the_scan_spans_count_the_files_read(sharded_run):
+    res, events = sharded_run
+    scans = [s for _, g in _groups(events) for s in g
+             if s["name"] == "task.scan"]
+    assert len(scans) > 1
+    # each scan shard reads its own files; together the table's 8
+    assert sum(s["attrs"]["files"] for s in scans) == 8
+    for s in scans:
+        assert s["attrs"]["files"] == len(res.plan.tasks[s["task"]].files)
 
 
 def test_the_unread_events_are_gone(sharded_run):
